@@ -123,7 +123,9 @@ def spmm_apply(
     :mod:`repro.kernels.autotune` when not given explicitly.
 
     Backends: ``"stream"`` (alias ``"jnp"``, the chunked-scan fallback),
-    ``"pallas"`` / ``"pallas_interpret"`` (row-segmented kernel),
+    ``"pallas"`` (row-segmented kernel, compiled for a TPU; JAX refuses it
+    on other backends) / ``"pallas_interpret"`` (the same kernel in the
+    Pallas interpreter),
     ``"dense"`` (scatter-into-dense + one matmul,
     :mod:`repro.kernels.dense_spmm`), and ``"auto"`` — a trace-time read of
     the per-signature backend decision cached by
@@ -140,10 +142,6 @@ def spmm_apply(
         cfg = autotune.lookup(sig, d=h.shape[-1])
         backend = cfg.backend
         obs.get_ledger().note_backend(sig, backend)
-        if backend == "pallas":
-            from repro.kernels import ops as kops
-            if not kops.on_tpu():
-                backend = "pallas_interpret"
         if chunk is None:
             chunk = cfg.chunk
     if backend == "pallas" or backend == "pallas_interpret":

@@ -205,6 +205,8 @@ class StreamingInference:
 
     def __init__(self, graph: GraphData, model, params,
                  cfg: StreamConfig = StreamConfig()):
+        from repro.kernels.ops import require_tpu
+        require_tpu(cfg.backend)
         self.module = MODELS[model] if isinstance(model, str) else model
         self.cfg = cfg
         self.params = params
@@ -467,16 +469,10 @@ class StreamingInference:
         return len(self._parts["exact"])
 
     # -------------------------------------------------------------- spmm
-    def _resolved_backend(self) -> str:
-        if self.cfg.backend == "pallas":
-            from repro.kernels import ops as kops
-            if not kops.on_tpu():
-                return "pallas_interpret"
-        return self.cfg.backend
 
     def _warmup_autotune(self) -> None:
         from repro.kernels import autotune
-        backend = self._resolved_backend()
+        backend = self.cfg.backend
         bm = bk = self.cfg.block
         for mode, (nb_pad, s_pad, g_pad) in self._pads.items():
             for d in sorted(set(self._dims)):
@@ -497,7 +493,7 @@ class StreamingInference:
             return cached
         nb_pad, s_pad, g_pad = self._pads[mode]
         bm, bk = self.host.bm, self.host.bk
-        backend = self._resolved_backend()
+        backend = self.cfg.backend
         pre_fn = pre[0] if pre is not None else None
 
         def fn(blocks, sel, rows, cols, rptr, n_active, h, pre_params):

@@ -66,6 +66,9 @@ from repro import obs
 
 logger = logging.getLogger(__name__)
 
+# TPU lane width. The Pallas kernel runs at the feature width padded up to
+# a multiple of it, and its column tile ``bd`` is a multiple of it.
+LANE = 128
 CHUNK_CANDIDATES = (8, 16, 32, 64, 128)
 BD_CANDIDATES = (128, 256, 512)
 DEFAULT_CHUNK = 32
@@ -73,8 +76,8 @@ DEFAULT_BD = 512
 # Sweep-time caps: candidates are timed at the bucket's representative
 # shape clipped to these, keeping any single sweep sub-second-ish on CPU
 # while preserving the relative ordering of tile configs. SWEEP_MAX_D
-# equals max(BD_CANDIDATES) so clipping d never removes a bd candidate
-# from the sweep space.
+# clips only the streaming and dense sweeps; the Pallas sweep runs at the
+# padded width so its ``bd`` candidates divide it.
 SWEEP_MAX_S = 1024
 SWEEP_MAX_BLOCKS = 64
 SWEEP_MAX_D = 512
@@ -126,6 +129,11 @@ def _pow2_ceil(x: int) -> int:
     return 1 << max(0, (int(x) - 1).bit_length())
 
 
+def padded_width(d: int) -> int:
+    """Feature width the SpMM kernel runs at: ``d`` rounded up to LANE."""
+    return -(-int(d) // LANE) * LANE
+
+
 def _density_band(s_pad: int, n_row_blocks: int, n_col_blocks: int) -> str:
     dens = s_pad / max(1, n_row_blocks * n_col_blocks)
     for edge in (0.02, 0.05, 0.1, 0.25, 0.5, 1.0):
@@ -136,8 +144,14 @@ def _density_band(s_pad: int, n_row_blocks: int, n_col_blocks: int) -> str:
 
 def signature(backend: str, *, bm: int, bk: int, d: int, s_pad: int,
               n_row_blocks: int, n_col_blocks: int) -> str:
-    """Bucket an operand's dispatch statics into a cache key."""
-    return (f"{backend}|bm{bm}|bk{bk}|d{_pow2_ceil(d)}|s{_pow2_ceil(s_pad)}"
+    """Bucket an operand's dispatch statics into a cache key.
+
+    ``d`` keys by :func:`padded_width`, so every width that shares a
+    signature runs the kernel at the same padded width and a tuned ``bd``
+    divides it.
+    """
+    return (f"{backend}|bm{bm}|bk{bk}|d{padded_width(d)}"
+            f"|s{_pow2_ceil(s_pad)}"
             f"|rb{_pow2_ceil(n_row_blocks)}"
             f"|dens{_density_band(s_pad, n_row_blocks, n_col_blocks)}")
 
@@ -455,13 +469,18 @@ def _sweep(backend: str, *, bm: int, bk: int, d: int, s_pad: int,
         us = _bench(fn) * 1e6
         best = (us, SpmmConfig(bd=default_config(d).bd, chunk=DEFAULT_CHUNK,
                                source="swept", backend="dense"))
-    else:
+    elif backend in ("pallas", "pallas_interpret"):
         from repro.kernels import ops as kops
         from repro.sparse.bcoo import host_row_ptr
-        interpret = backend == "pallas_interpret" or not kops.on_tpu()
+        kops.require_tpu(backend)
+        interpret = backend == "pallas_interpret"
         rptr = jnp.asarray(host_row_ptr(np.asarray(rows), rb_rep))
-        cands = [bd for bd in BD_CANDIDATES if bd <= d_rep and
-                 d_rep % bd == 0] or [d_rep]
+        # Timed at the padded width the kernel really runs at (never
+        # clipped), so every candidate divides it.
+        dp = padded_width(d)
+        h = jnp.asarray(rng.standard_normal((cb_rep * bk, dp))
+                        .astype(np.float32))
+        cands = [bd for bd in BD_CANDIDATES if dp % bd == 0]
         for bd in cands:
             fn = lambda b=bd: kops.bcoo_spmm(  # noqa: E731
                 blocks, sel, rows, cols, h, n_row_blocks=rb_rep,
@@ -471,6 +490,8 @@ def _sweep(backend: str, *, bm: int, bk: int, d: int, s_pad: int,
                              backend="pallas")
             if best is None or us < best[0]:
                 best = (us, cfg)
+    else:
+        raise ValueError(f"unknown SpMM backend {backend!r}")
     # raw requested name ("jnp", "pallas_interpret", ...): provenance says
     # what was timed; get() canonicalizes when serving the dispatch choice
     prov = {"backend": backend,

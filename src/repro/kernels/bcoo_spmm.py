@@ -41,8 +41,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 
 @functools.partial(
     jax.jit,
@@ -137,8 +135,8 @@ def bcoo_spmm(
         out_ref[...] = y.astype(out_ref.dtype)
 
     in_specs = [
-        pl.BlockSpec(memory_space=pltpu.ANY),   # blocks stay in HBM
-        pl.BlockSpec(memory_space=pltpu.ANY),   # hb stays in HBM
+        pl.BlockSpec(memory_space=pl.ANY),   # blocks stay in HBM
+        pl.BlockSpec(memory_space=pl.ANY),   # hb stays in HBM
     ]
     args = [blocks, hb]
     if has_bias:
@@ -165,6 +163,7 @@ def bcoo_spmm(
         body,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_row_blocks * bm, d), h.dtype),
-        compiler_params=tpu_compiler_params(("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(sel, col_ids, row_ptr, *args)

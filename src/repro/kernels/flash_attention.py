@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -122,8 +121,8 @@ def flash_attention_fwd(
         body,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * nq, tq, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
-            ("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q_off, qm, km, vm)
     return out.reshape(b, nq, tq, hd).transpose(0, 2, 1, 3)

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 
 @functools.partial(
     jax.jit, static_argnames=("bk", "bm", "bq", "interpret", "transpose_lhs"))
@@ -71,7 +69,7 @@ def gather_matmul(
         body,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, q), jnp.float32),
-        compiler_params=tpu_compiler_params(
-            ("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(idx, x, g).astype(x.dtype)

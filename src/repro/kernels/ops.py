@@ -1,8 +1,11 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On this CPU container the kernels run in ``interpret=True``; on TPU the same
-call sites compile to Mosaic. ``default_backend()`` picks automatically, and
-``repro.core`` ops accept an explicit ``backend`` string everywhere.
+The kernels compile to Mosaic on a TPU. ``interpret=True`` runs the same
+kernel bodies in the Pallas interpreter, which is how tests exercise them
+on a CPU; it is never chosen implicitly. ``repro.core`` ops name the
+lowering with an explicit ``backend`` string (``"pallas"`` or
+``"pallas_interpret"``), and :func:`require_tpu` turns a ``"pallas"``
+request on a host without a TPU into an error instead of a silent switch.
 
 SpMM dispatch consults :mod:`repro.kernels.autotune`: when ``bd`` is not
 given explicitly, the per-signature config cache supplies the tuned dense
@@ -10,16 +13,12 @@ column tile (or a heuristic default if the signature was never swept).
 """
 from __future__ import annotations
 
-import logging
-import math
-
 import jax
+import jax.numpy as jnp
 
 from repro import obs
 from repro.kernels import autotune
-
-logger = logging.getLogger(__name__)
-_bd_fallback_logged: set[tuple[int, int]] = set()
+from repro.kernels.autotune import LANE, padded_width
 from repro.kernels.bcoo_spmm import bcoo_spmm as _bcoo_spmm_pallas
 from repro.kernels.gather_matmul import gather_matmul as _gather_matmul_pallas
 
@@ -33,52 +32,62 @@ def default_backend() -> str:
     return "pallas" if on_tpu() else "jnp"
 
 
+def require_tpu(backend: str) -> None:
+    """Refuse a compiled-kernel backend where no TPU is attached."""
+    if backend == "pallas" and not on_tpu():
+        raise RuntimeError(
+            f"backend 'pallas' compiles the SpMM kernel for a TPU, but JAX's "
+            f"default backend is {jax.default_backend()!r}; pass "
+            "'pallas_interpret' to run the kernel in the Pallas interpreter")
+
+
 def bcoo_spmm(blocks, sel, row_ids, col_ids, h, *, n_row_blocks, bm, bk,
               bd: int | None = None, row_ptr=None, bias=None, residual=None,
-              relu: bool = False, interpret: bool | None = None):
-    if interpret is None:
-        interpret = not on_tpu()
+              relu: bool = False, interpret: bool = False):
+    """Block-COO SpMM at any feature width ``d``.
+
+    Columns of ``h``, ``bias`` and ``residual`` are zero-padded up to
+    :func:`padded_width` (the kernel's column slabs are LANE-aligned), the
+    kernel runs at the padded width, and the output is sliced back to
+    ``d``. Zero columns stay zero through the epilogue, so the slice is
+    exact.
+    """
     d = h.shape[-1]
+    dp = padded_width(d)
     if bd is None:
+        backend = "pallas_interpret" if interpret else "pallas"
         sig = autotune.signature(
-            "pallas_interpret" if interpret else "pallas",
-            bm=bm, bk=bk, d=d, s_pad=sel.shape[0],
+            backend, bm=bm, bk=bk, d=d, s_pad=sel.shape[0],
             n_row_blocks=n_row_blocks, n_col_blocks=h.shape[0] // bk)
-        bd = autotune.lookup(sig, d=d).bd
-        obs.get_ledger().note_backend(
-            sig, "pallas_interpret" if interpret else "pallas")
-    bd = min(bd, d)
-    if d % bd:
-        # A tuned bd from a pow2 shape bucket may not divide this exact d;
-        # fall back to the largest common tile rather than failing dispatch.
-        # Counted + logged once per (bd, d): a persistent fallback means the
-        # tuned tile never actually serves this shape.
-        fell = math.gcd(bd, d)
-        obs.get_registry().counter("autotune.bd_fallback", bd=bd, d=d)
-        if (bd, d) not in _bd_fallback_logged:
-            _bd_fallback_logged.add((bd, d))
-            logger.info(
-                "tuned bd=%d does not divide d=%d; dispatching gcd tile "
-                "bd=%d instead", bd, d, fell)
-        bd = fell
-    return _bcoo_spmm_pallas(
+        bd = autotune.lookup(sig, d=dp).bd
+        obs.get_ledger().note_backend(sig, backend)
+    bd = min(bd, dp)
+    if bd % LANE or dp % bd:
+        raise ValueError(
+            f"column tile bd={bd} must be a multiple of {LANE} that divides "
+            f"the padded width {dp} (d={d})")
+    if dp != d:
+        pad = [(0, 0), (0, dp - d)]
+        h = jnp.pad(h, pad)
+        if bias is not None:
+            bias = jnp.pad(bias, pad[1:])
+        if residual is not None:
+            residual = jnp.pad(residual, pad)
+    out = _bcoo_spmm_pallas(
         blocks, sel, row_ids, col_ids, h,
         n_row_blocks=n_row_blocks, bm=bm, bk=bk, bd=bd, row_ptr=row_ptr,
         bias=bias, residual=residual, relu=relu, interpret=interpret)
+    return out[:, :d] if dp != d else out
 
 
 def gather_matmul(x, g, idx, *, bk: int = 128, transpose_lhs: bool = True,
-                  interpret: bool | None = None):
-    if interpret is None:
-        interpret = not on_tpu()
+                  interpret: bool = False):
     return _gather_matmul_pallas(
         x, g, idx, bk=bk, transpose_lhs=transpose_lhs, interpret=interpret)
 
 
 def flash_attention(q, k, v, *, q_offset=0, causal=True, window=None,
-                    interpret: bool | None = None):
+                    interpret: bool = False):
     from repro.kernels.flash_attention import flash_attention_fwd
-    if interpret is None:
-        interpret = not on_tpu()
     return flash_attention_fwd(q, k, v, q_offset=q_offset, causal=causal,
                                window=window, interpret=interpret)

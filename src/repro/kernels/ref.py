@@ -20,8 +20,10 @@ def bcoo_spmm_ref(
     hb = h.reshape(-1, bk, d)
     tiles = blocks[sel]                                  # (s_pad, bm, bk)
     gathered = hb[col_ids]                               # (s_pad, bk, d)
+    # f32 accumulation at least; float64 operands stay float64.
     part = jnp.einsum("sij,sjd->sid", tiles, gathered,
-                      preferred_element_type=jnp.float32)
+                      preferred_element_type=jnp.promote_types(
+                          h.dtype, jnp.float32))
     out = jax.ops.segment_sum(part, row_ids, num_segments=n_row_blocks)
     return out.reshape(n_row_blocks * bm, d).astype(h.dtype)
 

@@ -6,19 +6,27 @@ Multi-pod:  (pod=2, data=16, model=16) — 512 chips.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axes: the engine indexes stacked,
+    sharded arrays, which Explicit axes (JAX's default) reject."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_test_mesh(n_devices: int | None = None, model: int = 2):
     """Small mesh over however many (host) devices exist — tests only."""
     n = n_devices or len(jax.devices())
     assert n % model == 0, (n, model)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _mesh((n // model, model), ("data", "model"))
 
 
 def make_dp_mesh(n_devices: int | None = None):
@@ -36,7 +44,7 @@ def make_dp_mesh(n_devices: int | None = None):
             f"requested data-parallel degree {n} > {avail} visible "
             "devices (set XLA_FLAGS=--xla_force_host_platform_device_"
             f"count={n} before importing jax to simulate)")
-    return jax.make_mesh((n,), ("data",), devices=jax.devices()[:n])
+    return _mesh((n,), ("data",), devices=jax.devices()[:n])
 
 
 def parse_mesh_spec(spec: str):
@@ -53,7 +61,7 @@ def parse_mesh_spec(spec: str):
         name, _, size = p.partition(":")
         names.append(name)
         sizes.append(int(size))
-    return jax.make_mesh(tuple(sizes), tuple(names))
+    return _mesh(tuple(sizes), tuple(names))
 
 
 def dp_axes(mesh, global_batch: int):

@@ -28,6 +28,7 @@ import numpy as np
 from repro import obs
 from repro.graphs.datasets import DATASETS, load_dataset
 from repro.infer import NodeServer, ServeFrontend, StreamConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.gnn import MODELS
 from repro.obs import slo as slo_mod
 from repro.train.loop import GNNTrainer, TrainConfig
@@ -116,6 +117,7 @@ def main():
     obs.add_cli_flags(ap)
     slo_mod.add_cli_flags(ap)
     args = ap.parse_args()
+    enable_compile_cache()
     ob = obs.setup_from_args(args)
     monitor = slo_mod.monitor_from_args(args)
     if monitor is not None:

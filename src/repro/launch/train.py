@@ -55,6 +55,7 @@ from repro import obs  # noqa: E402
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs import get_arch, make_batch, smoke_config
 from repro.graphs.datasets import DATASETS, load_dataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.lm.backbone import init_params
 from repro.pipeline import MinibatchConfig, MinibatchTrainer
 from repro.train.lm_steps import make_train_step
@@ -201,7 +202,8 @@ def run_lm(args) -> dict:
     return {"losses": losses, "params": params}
 
 
-def main():
+def main(argv: list[str] | None = None) -> dict:
+    """Parse ``argv`` (default: the command line), run, return the result."""
     ap = argparse.ArgumentParser()
     sub = ap.add_subparsers(dest="cmd", required=True)
 
@@ -307,8 +309,9 @@ def main():
     obs.add_cli_flags(l)
     l.set_defaults(fn=run_lm)
 
-    args = ap.parse_args()
-    args.fn(args)
+    args = ap.parse_args(argv)
+    enable_compile_cache()
+    return args.fn(args)
 
 
 if __name__ == "__main__":

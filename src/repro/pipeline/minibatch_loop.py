@@ -87,13 +87,8 @@ def tune_buckets(pool: SubgraphPool, cfg, dims: dict[str, int],
     once per bucket (and persists across processes via the JSON cache).
     """
     from repro.kernels import autotune
-    from repro.kernels import ops as kops
 
-    # Tune under the backend dispatch will actually resolve: "pallas"
-    # off-TPU runs (and signs its lookups) as "pallas_interpret".
     backend = cfg.backend
-    if backend == "pallas" and not kops.on_tpu():
-        backend = "pallas_interpret"
     # feat_dim covers layer-0 SpMMs over raw features (GraphSAGE).
     dim_set = sorted({cfg.hidden, n_classes, pool.feat_dim,
                       *dims.values()})

@@ -41,6 +41,7 @@ from repro.obs.sentinel import CompileSentinel, jit_compiles  # noqa: F401
                                           # (jit_compiles re-exported: it
                                           # lived here before repro.obs)
 from repro.graphs.synthetic import GraphData
+from repro.kernels.ops import require_tpu
 from repro.models.gnn import MODELS
 from repro.models.gnn.common import build_operands
 from repro.train.metrics import metric_fn
@@ -413,6 +414,7 @@ class Engine:
                  compress_block: int = 128,
                  overlap_allreduce: bool = False,
                  overlap_buckets: int = 4, graph=None):
+        require_tpu(cfg.backend)
         self.cfg = cfg
         self.source = source
         self.module = MODELS[cfg.model]

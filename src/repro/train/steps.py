@@ -24,7 +24,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.sampling import row_norms
@@ -259,7 +259,7 @@ def make_dp_gnn_steps(module, opt, dims: dict[str, int], rsc_names,
             body, mesh=mesh,
             in_specs=(P(), P(axis), P(axis), P(axis), P(axis)),
             out_specs=(P(), P(), P(axis), P(axis)),
-            check_rep=False)
+            check_vma=False)
         lv, grads, norms, err = sharded(params, err, ops, plans, keys)
         params, opt_state = _apply(params, opt_state, grads)
         return params, opt_state, lv, norms, err
@@ -274,7 +274,7 @@ def make_dp_gnn_steps(module, opt, dims: dict[str, int], rsc_names,
             body, mesh=mesh,
             in_specs=(P(), P(axis), P(axis), P(axis)),
             out_specs=(P(), P(), P(axis)),
-            check_rep=False)
+            check_vma=False)
         lv, grads, err = sharded(params, err, ops, keys)
         params, opt_state = _apply(params, opt_state, grads)
         return params, opt_state, lv, err

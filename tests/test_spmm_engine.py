@@ -139,6 +139,61 @@ def test_epilogue_gradients_match_unfused():
                                    atol=1e-4, rtol=1e-4)
 
 
+@pytest.mark.parametrize("d", [41, 602])
+@pytest.mark.parametrize("bias,residual,relu", [
+    (False, False, False), (True, True, True), (False, True, False)])
+def test_padded_width_dispatch_matches_ref(d, bias, residual, relu):
+    """Widths that are not a multiple of the 128-lane tile (GCN's 41
+    classes, GraphSAGE's 602 raw features) dispatch through column padding
+    and come back at width ``d``, equal to the oracle."""
+    from repro.kernels import ops
+
+    a, plan = _plan_operands(64, 0.2, seed=13, keep_frac=0.5)
+    rng = np.random.default_rng(14)
+    h = jnp.asarray(rng.standard_normal((a.n_cols, d)).astype(np.float32))
+    b = (jnp.asarray(rng.standard_normal(d).astype(np.float32))
+         if bias else None)
+    r = (jnp.asarray(rng.standard_normal((a.n_rows, d)).astype(np.float32))
+         if residual else None)
+    out = ops.bcoo_spmm(a.blocks, plan.sel, plan.row_ids, plan.col_ids, h,
+                        n_row_blocks=a.n_row_blocks, bm=a.bm, bk=a.bk,
+                        row_ptr=plan.row_ptr, bias=b, residual=r, relu=relu,
+                        interpret=True)
+    ref = np.asarray(_ref(a, plan, h))
+    if bias:
+        ref = ref + np.asarray(b)[None, :]
+    if residual:
+        ref = ref + np.asarray(r)
+    if relu:
+        ref = np.maximum(ref, 0.0)
+    assert out.shape == (a.n_rows, d)
+    np.testing.assert_allclose(np.asarray(out), ref, atol=1e-4, rtol=1e-4)
+
+
+def test_pallas_backend_without_tpu_raises():
+    """Asking for the compiled kernel where no TPU is attached fails, at
+    the entry point and at dispatch; it never becomes interpret mode."""
+    from repro.kernels import ops
+    from repro.train.loop import GNNTrainer, TrainConfig
+
+    assert jax.default_backend() != "tpu"
+    a, plan = _plan_operands(32, 0.2, seed=15)
+    h = jnp.ones((a.n_cols, 16), jnp.float32)
+    with pytest.raises(RuntimeError, match="pallas_interpret"):
+        ops.require_tpu("pallas")
+    with pytest.raises(ValueError, match="interpret"):
+        spmm_apply(a.blocks, plan, h, a.n_row_blocks, a.bm, a.bk, "pallas")
+    with pytest.raises(RuntimeError, match="pallas_interpret"):
+        autotune.get_or_tune("pallas", bm=8, bk=8, d=16, s_pad=32,
+                             n_row_blocks=4, n_col_blocks=4, persist=False)
+    from repro.graphs.synthetic import sbm_graph
+    g = sbm_graph(n_nodes=64, n_clusters=2, avg_degree=4, feat_dim=8,
+                  seed=0)
+    with pytest.raises(RuntimeError, match="pallas_interpret"):
+        GNNTrainer(TrainConfig(backend="pallas", block=32, hidden=8,
+                               n_layers=2, epochs=1), g)
+
+
 if HAS_HYPOTHESIS:
     @settings(max_examples=15, deadline=None)
     @given(n=st.integers(24, 72), density=st.floats(0.02, 0.5),
