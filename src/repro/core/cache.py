@@ -110,6 +110,11 @@ class PlanCache:
 
         grad_row_norms[name]: (n_rows_of_∇H,) — ‖∇H^{(l+1)}_{i,:}‖₂ per node.
         """
+        tracer = obs.get_tracer()
+        with tracer.span("plan.refresh"):
+            return self._refresh(grad_row_norms, tracer)
+
+    def _refresh(self, grad_row_norms, tracer) -> Allocation:
         t0 = time.perf_counter()
         names = list(self.ops.keys())
         layers = []
@@ -130,14 +135,16 @@ class PlanCache:
         else:
             alloc = alloc_fn(layers, self.budget_frac)
 
-        for n, spec, keep in zip(names, layers, alloc.keep):
-            e = self.ops[n]
-            e.plan = build_plan(e.meta, keep, e.at.n_row_blocks,
-                                e.at.s_total, bucket=self._bucket(e.at))
-            if e.last_scores is not None:
-                self.stats.auc_history.append(
-                    topk_overlap_auc(e.last_scores, keep))
-            e.last_scores = spec.scores
+        # Plan construction and its upload to the device.
+        with tracer.span("plan.build"):
+            for n, spec, keep in zip(names, layers, alloc.keep):
+                e = self.ops[n]
+                e.plan = build_plan(e.meta, keep, e.at.n_row_blocks,
+                                    e.at.s_total, bucket=self._bucket(e.at))
+                if e.last_scores is not None:
+                    self.stats.auc_history.append(
+                        topk_overlap_auc(e.last_scores, keep))
+                e.last_scores = spec.scores
         self.stats.refreshes += 1
         self.stats.allocations += 1
         self.stats.k_history.append(alloc.k.copy())

@@ -177,10 +177,18 @@ def spmm_apply(
     return out
 
 
-def _exact_fwd(a: BlockCOO, h: jax.Array, backend: str,
-               bias=None, residual=None, relu=False) -> jax.Array:
+def _exact_apply(a: BlockCOO, h: jax.Array, backend: str,
+                 bias=None, residual=None, relu=False) -> jax.Array:
     return spmm_apply(a.blocks, exact_plan(a), h, a.n_row_blocks, a.bm, a.bk,
                       backend, bias=bias, residual=residual, relu=relu)
+
+
+# Trace-time names: each apply's ops carry the direction in their location
+# (``.../gcn/spmm0/spmm_fwd/...``), and so do the device ops in a profile.
+def _exact_fwd(a: BlockCOO, h: jax.Array, backend: str,
+               bias=None, residual=None, relu=False) -> jax.Array:
+    with jax.named_scope("spmm_fwd"):
+        return _exact_apply(a, h, backend, bias, residual, relu)
 
 
 # cfg = (backend, relu, has_bias, has_residual) — static dispatch tuple.
@@ -204,8 +212,9 @@ def _rsc_bwd(cfg, res, g):
     a, at, bwd_plan, mask = res
     gp = jnp.where(mask, g, 0) if relu else g
     # ∇J = SpMM_sampled(Ãᵀ, ∇H_pre): only the tiles the plan kept.
-    dh = spmm_apply(at.blocks, bwd_plan, gp, at.n_row_blocks, at.bm, at.bk,
-                    backend)
+    with jax.named_scope("spmm_bwd_sampled"):
+        dh = spmm_apply(at.blocks, bwd_plan, gp, at.n_row_blocks, at.bm,
+                        at.bk, backend)
     dbias = jnp.sum(gp, axis=0) if has_bias else None
     dres = gp if has_residual else None
     return (_zero_cot(a), _zero_cot(at), _zero_cot(bwd_plan), dh, dbias, dres)
@@ -247,7 +256,8 @@ def _eb_bwd(cfg, res, g):
     backend, relu, has_bias, has_residual = cfg
     a, at, mask = res
     gp = jnp.where(mask, g, 0) if relu else g
-    dh = _exact_fwd(at, gp, backend)
+    with jax.named_scope("spmm_bwd_exact"):
+        dh = _exact_apply(at, gp, backend)
     dbias = jnp.sum(gp, axis=0) if has_bias else None
     dres = gp if has_residual else None
     return (_zero_cot(a), _zero_cot(at), dh, dbias, dres)

@@ -166,4 +166,5 @@ def bcoo_spmm(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="bcoo_spmm",
     )(sel, col_ids, row_ptr, *args)

@@ -131,7 +131,7 @@ def build_operands(
 
 
 def spmm_op(a: BlockCOO, at: BlockCOO, h: jax.Array,
-            plan: SamplePlan | None, backend: str, *,
+            plan: SamplePlan | None, backend: str, *, name: str,
             bias: jax.Array | None = None,
             residual: jax.Array | None = None,
             relu: bool = False) -> jax.Array:
@@ -143,12 +143,16 @@ def spmm_op(a: BlockCOO, at: BlockCOO, h: jax.Array,
     exactly (see ``core.rsc_spmm``). The gradient TAP of each SpMM output
     is fused as the ``residual`` term — algebraically identical to the
     post-hoc ``+ tap``.
+
+    ``name`` (the op's plan-cache name, ``gcn/spmm0``) scopes the call at
+    trace time: its ops, forward and backward, carry it in their location.
     """
-    if plan is None:
-        return exact_spmm(a, at, h, backend, bias=bias, residual=residual,
-                          relu=relu)
-    return rsc_spmm(a, at, plan, h, backend, bias=bias, residual=residual,
-                    relu=relu)
+    with jax.named_scope(name):
+        if plan is None:
+            return exact_spmm(a, at, h, backend, bias=bias,
+                              residual=residual, relu=relu)
+        return rsc_spmm(a, at, plan, h, backend, bias=bias,
+                        residual=residual, relu=relu)
 
 
 # ------------------------------ nn primitives ------------------------------

@@ -96,7 +96,7 @@ def apply(params, ops: C.GraphOperands, taps: dict, plans: dict | None,
         # fuses into the SpMM whenever nothing (batchnorm) sits between.
         fuse_relu = l < n_layers - 1 and params["bn"][l] is None
         hp = C.spmm_op(ops.a, ops.at, j, plans.get(name), backend,
-                       residual=taps.get(name), relu=fuse_relu)
+                       name=name, residual=taps.get(name), relu=fuse_relu)
         if l < n_layers - 1 and params["bn"][l] is not None:
             hp = jax.nn.relu(C.batchnorm(params["bn"][l], hp, valid))
         h = hp

@@ -107,7 +107,7 @@ def apply(params, ops: C.GraphOperands, taps: dict, plans: dict | None,
         # Tap fused as the epilogue residual (ReLU can't fuse here: the
         # (1−β)I + βW mix sits between the SpMM and the activation).
         p = C.spmm_op(ops.a, ops.at, h, plans.get(name), backend,
-                      residual=taps.get(name))
+                      name=name, residual=taps.get(name))
         beta = math.log(lam / (l + 1) + 1.0)
         ht = (1.0 - alpha) * p + alpha * h0
         hp = (1.0 - beta) * ht + beta * C.dense(params["w"][l], ht)

@@ -97,7 +97,7 @@ def apply(params, ops: C.GraphOperands, taps: dict, plans: dict | None,
         # Tap fused as the epilogue residual (the neigh dense layer sits
         # between the SpMM and the activation, so ReLU stays outside).
         m = C.spmm_op(ops.am, ops.amt, h, plans.get(name), backend,
-                      residual=taps.get(name))
+                      name=name, residual=taps.get(name))
         hp = C.dense(params["self"][l], h) + C.dense(params["neigh"][l], m)
         if l < n_layers - 1:
             if params["bn"][l] is not None:

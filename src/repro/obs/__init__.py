@@ -1,6 +1,9 @@
 """Unified telemetry: metrics registry, tracer, ledger, compile sentinel.
 
-Zero-dependency observability substrate for the whole stack. One
+Observability substrate for the whole stack. Its registry, tracer,
+ledger and sentinel use the standard library alone; the tracer imports
+``jax.profiler`` lazily, on its first enabled span, for its profiler
+sink. One
 process-wide :class:`Observability` bundle holds a
 :class:`~repro.obs.registry.MetricsRegistry`, a
 :class:`~repro.obs.trace.Tracer` and a
@@ -14,10 +17,11 @@ process-wide :class:`Observability` bundle holds a
     obs.get_ledger().snapshot()
 
 All default to DISABLED — every instrumentation site in the engine,
-pipeline, kernels and serving layers checks one attribute and returns,
-so the uninstrumented hot path pays (benchmarked in
-``benchmarks/obs_overhead.py``) well under 2%. Tests swap a fresh bundle
-in via :func:`reset`.
+pipeline, kernels and serving layers checks one attribute and returns.
+With the tracer on and the JAX profiler recording, the median training
+epoch on a TPU v5e (full-batch GCN and GraphSAGE on a 23,296-node
+Reddit-shaped graph) moved by less than its run-to-run spread. Tests
+swap a fresh bundle in via :func:`reset`.
 
 ``--metrics-port N`` (see :func:`add_cli_flags`) additionally starts the
 background HTTP exposition endpoint (:mod:`repro.obs.export`) serving

@@ -25,6 +25,13 @@ trace-annotated spans of each multi-thread trace are stitched into Chrome
 **flow events** (``ph: "s"/"t"/"f"``) so Perfetto draws one arrowed arc
 per request across the thread tracks.
 
+**Profiler sink:** while the tracer is enabled, ``span`` and ``span_in``
+also open a ``jax.profiler.TraceAnnotation`` of the same name, so the
+spans land in any profiler trace (``jax.profiler.trace``, the benchmark's
+``--trace 1``) on the profiler's own clock, over the device operations
+they launched. ``span_at`` is retroactive and stays in memory only.
+:data:`SPAN_NAMES` lists the spans the program opens.
+
 Timestamps are monotonic (``perf_counter``) microseconds from the
 tracer's construction. The event buffer is bounded (``max_events``);
 overflow drops newest events and counts them in ``dropped`` so a
@@ -45,6 +52,20 @@ import threading
 
 from repro.obs import context as trace_context
 from repro.obs.clock import perf_now
+
+#: Every span the program opens live, by name, as the profiler shows it.
+#: The training engine: ``rng`` (the step's key split), ``step`` >
+#: {``plan`` > ``plan.refresh`` > ``plan.build``, ``device_step`` >
+#: {``dispatch``, ``wait``}, ``norms`` (gradient row norms to the
+#: host)}, ``loss`` (the loss to the host),
+#: ``epoch_end`` (sentinel and ledger checks), ``probe``, ``eval`` >
+#: {``eval.logits``, ``eval.metric``}. Elsewhere: ``upload`` (prefetch
+#: thread), ``stream_layer`` > ``stream_partition`` (streaming inference),
+#: ``apply_update`` (serving).
+SPAN_NAMES = ("rng", "step", "plan", "plan.refresh", "plan.build",
+              "device_step", "dispatch", "wait", "norms", "loss",
+              "epoch_end", "probe", "eval", "eval.logits", "eval.metric",
+              "upload", "stream_layer", "stream_partition", "apply_update")
 
 
 class _NullSpan:
@@ -67,7 +88,7 @@ _NULL_SPAN = _NullSpan()
 
 class _Span:
     __slots__ = ("_tracer", "name", "args", "_t0", "_depth", "_parent",
-                 "_ctx")
+                 "_ctx", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict, ctx=None):
         self._tracer = tracer
@@ -92,11 +113,15 @@ class _Span:
                 self._ctx = cur.child()
         if self._ctx is not None:
             trace_context._push(self._ctx)
+        from jax.profiler import TraceAnnotation   # the profiler sink
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
         self._t0 = perf_now()
         return self
 
     def __exit__(self, *exc):
         t1 = perf_now()
+        self._ann.__exit__(None, None, None)
         self._tracer._stack().pop()
         ev = {
             "kind": "span",

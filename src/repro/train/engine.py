@@ -386,11 +386,15 @@ class FullGraphSource:
         pass
 
     def evaluate(self, eval_fn, mfn, params) -> tuple[float, float]:
-        logits = np.asarray(eval_fn(params, self.ops))
-        labels = np.asarray(self.ops.labels)
-        valid = np.arange(logits.shape[0]) < self.ops.n_valid
-        val = mfn(logits, labels, np.asarray(self.ops.val_mask) & valid)
-        test = mfn(logits, labels, np.asarray(self.ops.test_mask) & valid)
+        tracer = obs.get_tracer()
+        with tracer.span("eval.logits"):
+            logits = np.asarray(eval_fn(params, self.ops))
+        with tracer.span("eval.metric"):
+            labels = np.asarray(self.ops.labels)
+            valid = np.arange(logits.shape[0]) < self.ops.n_valid
+            val = mfn(logits, labels, np.asarray(self.ops.val_mask) & valid)
+            test = mfn(logits, labels,
+                       np.asarray(self.ops.test_mask) & valid)
         return val, test
 
 
@@ -624,7 +628,8 @@ class Engine:
                             if tracer.enabled else None)
                 reg.observe("engine.sample_ms",
                             (time.perf_counter() - t_fetch) * 1e3)
-                key, sub = jax.random.split(key)
+                with tracer.span("rng"):
+                    key, sub = jax.random.split(key)
                 approx = self.schedule.use_rsc(gstep)
                 use_rsc = cfg.rsc and approx
                 compress = (self.compress_grads
@@ -633,18 +638,22 @@ class Engine:
                 mode = "rsc" if use_rsc else "exact"
                 t0 = time.perf_counter()
                 with tracer.span_in(step_ctx, "step", step=gstep,
-                                    epoch=epoch, mode=mode) as sp:
+                                    epoch=epoch, mode=mode):
                     if use_rsc:
                         with tracer.span("plan"):
                             plans = self.planner.plans_for(
                                 tag, gstep, self.schedule)
                         with tracer.span("device_step", mode=mode):
-                            self.params, self.opt_state, lv, norms = \
-                                self.runner.rsc_step(
-                                    self.params, self.opt_state,
-                                    ops, plans, sub, compress)
-                            jax.block_until_ready(lv)
-                        self.planner.record(tag, norms)
+                            with tracer.span("dispatch"):
+                                self.params, self.opt_state, lv, norms = \
+                                    self.runner.rsc_step(
+                                        self.params, self.opt_state,
+                                        ops, plans, sub, compress)
+                            with tracer.span("wait"):
+                                jax.block_until_ready(lv)
+                        # The gradient row norms come to the host here.
+                        with tracer.span("norms"):
+                            self.planner.record(tag, norms)
                         if ledger.enabled:
                             # np.asarray on n_active forces a host sync on
                             # device-stacked DP plans — only when the
@@ -660,19 +669,21 @@ class Engine:
                             self._record_rsc_gauges(reg, plans, norms)
                     else:
                         with tracer.span("device_step", mode=mode):
-                            self.params, self.opt_state, lv = \
-                                self.runner.exact_step(
-                                    self.params, self.opt_state,
-                                    ops, sub, compress)
-                            jax.block_until_ready(lv)
+                            with tracer.span("dispatch"):
+                                self.params, self.opt_state, lv = \
+                                    self.runner.exact_step(
+                                        self.params, self.opt_state,
+                                        ops, sub, compress)
+                            with tracer.span("wait"):
+                                jax.block_until_ready(lv)
                         if ledger.enabled:
                             ledger.note_step(mode="exact")
                     dt = time.perf_counter() - t0
-                    sp.set(dur_ms=round(dt * 1e3, 3))
                 reg.observe("engine.step_ms", dt * 1e3, mode=mode)
                 reg.counter("engine.steps", mode=mode)
 
-                self.history["loss"].append(float(lv))
+                with tracer.span("loss"):
+                    self.history["loss"].append(float(lv))
                 self.history["step_time"].append(dt)
                 self.history["mode"].append("rsc" if use_rsc else "exact")
                 self.history["compress"].append(bool(compress))
@@ -692,19 +703,20 @@ class Engine:
                         aux=self._capture_state(epoch, bidx + 1, gstep, key,
                                                 (best_val, best_test)))
             skip = 0
-            if self.obs.enabled:
-                # Fold the planner's plan-cache statistics into the
-                # registry each epoch (summary()/per-shard stats used to
-                # be write-only), and enforce/record compile counts.
-                self.planner.publish(reg)
-            if (cfg.rsc and cfg.probe_every > 0
-                    and epoch % cfg.probe_every == 0
-                    and (reg.enabled or ledger.enabled)):
-                self._run_probes(epoch, reg)
-            if ledger.enabled:
-                ledger.end_epoch(epoch, reg)
-            ledger.check(f"epoch {epoch}", hard_fail=cfg.strict_budget)
-            self.sentinel.check(f"epoch {epoch}")
+            with tracer.span("epoch_end"):
+                if self.obs.enabled:
+                    # Fold the planner's plan-cache statistics into the
+                    # registry each epoch (summary()/per-shard stats used
+                    # to be write-only), and enforce/record compile counts.
+                    self.planner.publish(reg)
+                if (cfg.rsc and cfg.probe_every > 0
+                        and epoch % cfg.probe_every == 0
+                        and (reg.enabled or ledger.enabled)):
+                    self._run_probes(epoch, reg)
+                if ledger.enabled:
+                    ledger.end_epoch(epoch, reg)
+                ledger.check(f"epoch {epoch}", hard_fail=cfg.strict_budget)
+                self.sentinel.check(f"epoch {epoch}")
 
             if epoch % eval_every == 0 or epoch == epochs - 1:
                 with tracer.span("eval", epoch=epoch), \
