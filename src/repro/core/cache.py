@@ -26,7 +26,7 @@ import numpy as np
 from repro import obs
 from repro.core.allocator import (Allocation, LayerSpec, greedy_allocate,
                                   uniform_allocate)
-from repro.core.plan import SamplePlan, build_plan, full_plan
+from repro.core.plan import SamplePlan, host_plan
 from repro.core.sampling import block_scores, topk_overlap_auc
 from repro.sparse.bcoo import BlockCOO, BlockMeta
 
@@ -39,7 +39,13 @@ class OpEntry:
     d: int                  # hidden dim of this op's dense operand
     a_fro: float            # ‖Ã‖_F (Eq. 4a denominator, static half)
     plan: SamplePlan | None = None
+    row_ptr: np.ndarray | None = None   # host copy of plan.row_ptr
     last_scores: np.ndarray | None = None
+
+    def set_plan(self, plan: SamplePlan) -> None:
+        """Upload a host-built plan, keeping its row pointers on the host."""
+        self.plan = plan.to_device()
+        self.row_ptr = plan.row_ptr
 
 
 @dataclasses.dataclass
@@ -98,8 +104,8 @@ class PlanCache:
         entry = OpEntry(name=name, at=at, meta=meta, d=d, a_fro=a_fro)
         # Start exact (full plan) until the first refresh has gradient info.
         bucket = self.plan_pad if self.plan_pad is not None else 1
-        entry.plan = full_plan(meta, at.n_row_blocks, at.s_total,
-                               bucket=bucket)
+        entry.set_plan(host_plan(meta, None, at.n_row_blocks, at.s_total,
+                                 bucket=bucket))
         self.ops[name] = entry
 
     def plans(self) -> dict[str, SamplePlan]:
@@ -139,8 +145,8 @@ class PlanCache:
         with tracer.span("plan.build"):
             for n, spec, keep in zip(names, layers, alloc.keep):
                 e = self.ops[n]
-                e.plan = build_plan(e.meta, keep, e.at.n_row_blocks,
-                                    e.at.s_total, bucket=self._bucket(e.at))
+                e.set_plan(host_plan(e.meta, keep, e.at.n_row_blocks,
+                                     e.at.s_total, bucket=self._bucket(e.at)))
                 if e.last_scores is not None:
                     self.stats.auc_history.append(
                         topk_overlap_auc(e.last_scores, keep))

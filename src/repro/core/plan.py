@@ -56,6 +56,14 @@ class SamplePlan:
         """FLOPs of SpMM under this plan (Eq. 4b cost, block units)."""
         return 2 * self.n_active * bm * bk * d
 
+    def to_device(self) -> "SamplePlan":
+        """The same plan with its id lists uploaded."""
+        put = jax.numpy.asarray
+        return dataclasses.replace(
+            self, sel=put(self.sel), row_ids=put(self.row_ids),
+            col_ids=put(self.col_ids),
+            row_ptr=None if self.row_ptr is None else put(self.row_ptr))
+
     def bytes_moved(self, bm: int, bk: int, d: int) -> int:
         """f32 bytes an SpMM under this plan streams per call: each active
         tile plus the (bk, d) dense slab it gathers (ledger cost model —
@@ -87,6 +95,18 @@ def build_plan(
     sentinel: index of the zero tile (== s_total).
     bucket: pad s_pad up to a multiple of this (bounds recompilation count).
     """
+    return host_plan(meta, keep_col_blocks, n_row_blocks, sentinel,
+                     bucket).to_device()
+
+
+def host_plan(
+    meta: BlockMeta,
+    keep_col_blocks: np.ndarray | None,
+    n_row_blocks: int,
+    sentinel: int,
+    bucket: int = 1,
+) -> SamplePlan:
+    """:func:`build_plan` with its id lists left as host numpy arrays."""
     s_total = meta.row_ids.shape[0]
     if keep_col_blocks is None:
         keep_tile = np.ones(s_total, dtype=bool)
@@ -119,14 +139,10 @@ def build_plan(
         rows = np.concatenate([rows, np.full(pad, last_row, np.int32)])
         cols = np.concatenate([cols, np.zeros(pad, np.int32)])
 
-    row_ptr = host_row_ptr(rows, n_row_blocks)
     return SamplePlan(
-        sel=jax.numpy.asarray(sel),
-        row_ids=jax.numpy.asarray(rows),
-        col_ids=jax.numpy.asarray(cols),
-        s_pad=s_pad,
+        sel=sel, row_ids=rows, col_ids=cols, s_pad=s_pad,
         n_active=int(np.count_nonzero(keep_tile)),
-        row_ptr=jax.numpy.asarray(row_ptr),
+        row_ptr=host_row_ptr(rows, n_row_blocks),
     )
 
 

@@ -320,6 +320,16 @@ def lookup(sig: str, d: int | None = None) -> SpmmConfig:
     return default_config(d if d is not None else DEFAULT_BD)
 
 
+def served_bd(sig: str, d: int) -> int:
+    """The column tile :func:`lookup` serves for ``sig``, read without
+    touching its statistics, counters or log (host-side reporting)."""
+    _cache._load()
+    e = _cache.entries.get(sig)
+    if e is None:
+        return default_config(d).bd
+    return int(e.get("bd", DEFAULT_BD))
+
+
 def _bench(fn, iters: int = 3) -> float:
     import jax
     jax.block_until_ready(fn())          # compile + warm

@@ -15,11 +15,13 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro import obs
 from repro.kernels import autotune
 from repro.kernels.autotune import LANE, padded_width
 from repro.kernels.bcoo_spmm import bcoo_spmm as _bcoo_spmm_pallas
+from repro.kernels.bcoo_spmm import group_size, grouped_share
 from repro.kernels.gather_matmul import gather_matmul as _gather_matmul_pallas
 
 
@@ -39,6 +41,35 @@ def require_tpu(backend: str) -> None:
             f"backend 'pallas' compiles the SpMM kernel for a TPU, but JAX's "
             f"default backend is {jax.default_backend()!r}; pass "
             "'pallas_interpret' to run the kernel in the Pallas interpreter")
+
+
+def publish_grouping(row_ptr, operand, d: int, *, layer: str, op: str,
+                     backend: str) -> None:
+    """Report how often the kernel's tile groups engage for one call.
+
+    ``row_ptr`` is the host CSR-of-tiles pointer array of ``operand`` (a
+    block-COO operand, device or host) or of a plan over it, which
+    :func:`bcoo_spmm` walks at feature width ``d``. Sets the registry
+    gauges ``spmm.grouped_share{layer,op}`` (share of its tiles that run
+    in full groups) and ``spmm.group_k{layer,op}`` (tiles per group) for
+    the column tile dispatch serves that call. Other backends never run
+    the kernel and report nothing.
+    """
+    reg = obs.get_registry()
+    if not reg.enabled or backend not in ("pallas", "pallas_interpret"):
+        return
+    row_ptr = np.asarray(row_ptr)
+    dp = padded_width(d)
+    sig = autotune.signature(
+        backend, bm=operand.bm, bk=operand.bk, d=d, s_pad=int(row_ptr[-1]),
+        n_row_blocks=row_ptr.shape[0] - 1,
+        n_col_blocks=operand.n_col_blocks)
+    k = group_size(operand.bm, operand.bk,
+                   min(autotune.served_bd(sig, dp), dp),
+                   operand.blocks.dtype.itemsize)
+    reg.gauge("spmm.grouped_share", grouped_share(row_ptr, k),
+              layer=layer, op=op)
+    reg.gauge("spmm.group_k", k, layer=layer, op=op)
 
 
 def bcoo_spmm(blocks, sel, row_ids, col_ids, h, *, n_row_blocks, bm, bk,

@@ -60,12 +60,15 @@ class GraphOperands:
 
 @dataclasses.dataclass(frozen=True)
 class OperandMeta:
-    """Host metadata of the backward operands, for the PlanCache."""
+    """Host metadata of the operands: the backward ones for the PlanCache,
+    all four for the kernel's grouping gauges."""
 
     at_meta: BlockMeta
     amt_meta: BlockMeta
     a_fro: float
     am_fro: float
+    a_meta: BlockMeta
+    am_meta: BlockMeta
 
 
 def degree_sorted_arrays(adj, feats, labels, tr, va, te):
@@ -104,9 +107,9 @@ def build_operands(
 
     a_csr = sym_normalize(adj)
     am_csr = mean_normalize(adj)
-    a, _ = csr_to_bcoo(a_csr, bm, bk)
+    a, a_meta = csr_to_bcoo(a_csr, bm, bk)
     at, at_meta = csr_to_bcoo(a_csr.transpose(), bm, bk)
-    am, _ = csr_to_bcoo(am_csr, bm, bk)
+    am, am_meta = csr_to_bcoo(am_csr, bm, bk)
     amt, amt_meta = csr_to_bcoo(am_csr.transpose(), bm, bk)
 
     feats_p, labels_p, tr_p, va_p, te_p = pad_node_arrays(
@@ -126,6 +129,7 @@ def build_operands(
         at_meta=at_meta, amt_meta=amt_meta,
         a_fro=float(np.sqrt(np.sum(a_csr.val.astype(np.float64) ** 2))),
         am_fro=float(np.sqrt(np.sum(am_csr.val.astype(np.float64) ** 2))),
+        a_meta=a_meta, am_meta=am_meta,
     )
     return ops, meta
 

@@ -41,9 +41,10 @@ from repro.obs.sentinel import CompileSentinel, jit_compiles  # noqa: F401
                                           # (jit_compiles re-exported: it
                                           # lived here before repro.obs)
 from repro.graphs.synthetic import GraphData
-from repro.kernels.ops import require_tpu
+from repro.kernels.ops import publish_grouping, require_tpu
 from repro.models.gnn import MODELS
 from repro.models.gnn.common import build_operands
+from repro.sparse.bcoo import host_row_ptr
 from repro.train.metrics import metric_fn
 from repro.train.optimizer import Adam
 from repro.train.steps import (init_error_feedback, make_dp_gnn_steps,
@@ -160,16 +161,24 @@ class FullGraphPlanner:
         self.cache = PlanCache(budget_frac=cfg.budget,
                                step_frac=cfg.step_frac,
                                strategy=cfg.strategy)
+        self.backend = cfg.backend
         names = module.spmm_names(cfg.n_layers)
         dims = module.spmm_dims(cfg.n_layers, cfg.hidden, n_classes)
         for n in names:
             self.cache.register(n, at, meta, dims[n], fro)
+        self._publish_grouping()
         self._last_norms: dict[str, np.ndarray] | None = None
         self._refresh_norms: dict[str, np.ndarray] | None = None
+
+    def _publish_grouping(self) -> None:
+        for n, e in self.cache.ops.items():
+            publish_grouping(e.row_ptr, e.at, e.d, layer=n,
+                             op="spmm_bwd_sampled", backend=self.backend)
 
     def plans_for(self, tag, step: int, schedule: RSCSchedule):
         if self._last_norms is not None and schedule.refresh_due(step):
             self.cache.refresh(self._last_norms)
+            self._publish_grouping()
             self._refresh_norms = self._last_norms
         return self.cache.plans()
 
@@ -218,6 +227,7 @@ class FullGraphPlanner:
             return
         if state.get("refresh_norms") is not None:
             self.cache.refresh(state["refresh_norms"])
+            self._publish_grouping()
             self._refresh_norms = state["refresh_norms"]
         self.cache.stats.refreshes = state.get("refreshes",
                                                self.cache.stats.refreshes)
@@ -365,6 +375,15 @@ class FullGraphSource:
         self.num_classes = graph.num_classes
         self.feat_dim = graph.features.shape[1]
         self.mean_agg = module.uses_mean_agg()
+        fwd = "am" if self.mean_agg else "a"
+        dims = module.spmm_dims(cfg.n_layers, cfg.hidden, self.num_classes)
+        for n in module.spmm_names(cfg.n_layers):
+            for op, key in (("spmm_fwd", fwd), ("spmm_bwd_exact", fwd + "t")):
+                a = getattr(self.ops, key)
+                rows = getattr(self.meta, key + "_meta").row_ids
+                publish_grouping(host_row_ptr(rows, a.n_row_blocks), a,
+                                 dims[n], layer=n, op=op,
+                                 backend=cfg.backend)
 
     def planner_operand(self):
         """(at, meta, fro) of the backward operand the planner scores."""

@@ -5,7 +5,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.bcoo_spmm import bcoo_spmm
+from repro.kernels.bcoo_spmm import (GROUP_SIZES, VMEM_SHARE, bcoo_spmm,
+                                     group_size, grouped_share, working_set)
 from repro.kernels.gather_matmul import gather_matmul
 from repro.kernels.ref import bcoo_spmm_ref, gather_matmul_ref
 
@@ -67,6 +68,85 @@ def test_bcoo_spmm_empty_rows_zeroed():
     o = np.asarray(out)
     assert np.allclose(o[:bm], bk)
     assert np.allclose(o[bm:], 0.0)
+
+
+def _segments(rng, lens, n_cb, bm, bk):
+    """Rows of the given segment lengths over random distinct column
+    blocks; a segment of two or more tiles holds a sentinel second."""
+    rows, cols = [], []
+    for r, n in enumerate(lens):
+        cols += sorted(rng.choice(n_cb, n, replace=False).tolist())
+        rows += [r] * n
+    s = len(rows)
+    blocks = np.concatenate([rng.standard_normal((s, bm, bk)),
+                             np.zeros((1, bm, bk))]).astype(np.float32)
+    sel = np.arange(s, dtype=np.int32)
+    starts = np.cumsum([0] + list(lens[:-1]))
+    for start, n in zip(starts, lens):
+        if n >= 2:
+            sel[start + 1] = s
+    rows, cols = np.array(rows, np.int32), np.array(cols, np.int32)
+    row_ptr = np.searchsorted(rows, np.arange(len(lens) + 1)).astype(np.int32)
+    return blocks, sel, rows, cols, row_ptr
+
+
+_GROUP_CASES = [(k, n) for k in (1, 2, 4)
+                for n in sorted({0, 1, k - 1, k, k + 1, 2 * k + 1})]
+
+
+@pytest.mark.parametrize("k,n", _GROUP_CASES)
+@pytest.mark.parametrize("bias,residual,relu", [
+    (False, False, False), (True, True, True), (True, False, True)])
+def test_grouped_walk_matches_ref(k, n, bias, residual, relu):
+    """Segments shorter than, equal to and longer than a group of ``k``,
+    with a sentinel inside, an empty row between them, and the fused
+    epilogue: the grouped walk equals the oracle."""
+    bm = bk = 8
+    d = 16
+    n_cb = 10           # distinct column blocks for 2k + 1 tiles at k = 4
+    rng = np.random.default_rng(10 * k + n)
+    lens = [n, 0, n, 2 * k + 1]
+    blocks, sel, rows, cols, row_ptr = _segments(rng, lens, n_cb, bm, bk)
+    n_rb = len(lens)
+    h = jnp.asarray(rng.standard_normal((n_cb * bk, d)).astype(np.float32))
+    b = (jnp.asarray(rng.standard_normal(d).astype(np.float32))
+         if bias else None)
+    res = (jnp.asarray(rng.standard_normal((n_rb * bm, d))
+                       .astype(np.float32)) if residual else None)
+    out = bcoo_spmm(jnp.asarray(blocks), jnp.asarray(sel), jnp.asarray(rows),
+                    jnp.asarray(cols), h, n_row_blocks=n_rb, bm=bm, bk=bk,
+                    bd=8, row_ptr=jnp.asarray(row_ptr), bias=b, residual=res,
+                    relu=relu, group=k, interpret=True)
+    ref = bcoo_spmm_ref(jnp.asarray(blocks), jnp.asarray(sel),
+                        jnp.asarray(rows), jnp.asarray(cols), h,
+                        n_row_blocks=n_rb, bm=bm, bk=bk)
+    if bias:
+        ref = ref + b
+    if residual:
+        ref = ref + res
+    if relu:
+        ref = jnp.maximum(ref, 0.0)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("d", [128, 256, 640])
+@pytest.mark.parametrize("itemsize", [4, 2])
+def test_group_size_fits_vmem_share(d, itemsize):
+    """k is at least 1, its working set fits the VMEM share at the cells'
+    widths (column tile = width), and no larger candidate would fit."""
+    k = group_size(128, 128, d, itemsize)
+    assert k in GROUP_SIZES + (1,)
+    assert working_set(128, 128, d, itemsize, k) <= VMEM_SHARE
+    larger = [c for c in GROUP_SIZES if c > k]
+    assert all(working_set(128, 128, d, itemsize, c) > VMEM_SHARE
+               for c in larger)
+
+
+def test_grouped_share_counts_full_groups():
+    """Segments of 0, 3 and 9 tiles at k = 4: 8 of 12 run in full groups."""
+    assert grouped_share(np.array([0, 0, 3, 12]), 4) == 8 / 12
+    assert grouped_share(np.array([0, 0]), 4) == 0.0
 
 
 @pytest.mark.parametrize("n,m,q,bk,k_sel", [

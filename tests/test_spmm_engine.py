@@ -325,3 +325,48 @@ def test_autotune_concurrent_writers_never_corrupt(tmp_path):
     assert len(final) >= per_thread
     for e in final.values():
         assert e["bd"] == 128 and e["chunk"] == 16
+
+
+@pytest.mark.parametrize("backend", ["pallas_interpret", "jnp"])
+def test_full_graph_publishes_grouped_share(backend):
+    """The full-graph source and planner publish the kernel's tile-group
+    share per layer and direction from host row pointers; a backend that
+    never runs the kernel publishes none."""
+    from repro import obs
+    from repro.graphs.synthetic import sbm_graph
+    from repro.kernels.bcoo_spmm import grouped_share
+    from repro.models.gnn import MODELS
+    from repro.sparse.bcoo import host_row_ptr
+    from repro.train.engine import (FullGraphPlanner, FullGraphSource,
+                                    TrainConfig)
+
+    graph = sbm_graph(n_nodes=300, n_clusters=3, avg_degree=8, feat_dim=8,
+                      seed=0)
+    cfg = TrainConfig(model="gcn", n_layers=2, hidden=16, block=32,
+                      rsc=True, backend=backend)
+    gcn = MODELS["gcn"]
+    obs.reset(metrics=True)
+    try:
+        source = FullGraphSource(graph, cfg, gcn)
+        at, meta, fro = source.planner_operand()
+        planner = FullGraphPlanner(cfg, gcn, at, meta, fro,
+                                   source.num_classes)
+        reg = obs.get_registry()
+        a = source.ops.a
+        fwd = grouped_share(
+            host_row_ptr(source.meta.a_meta.row_ids, a.n_row_blocks), 8)
+        for layer in gcn.spmm_names(2):
+            ops_ = ("spmm_fwd", "spmm_bwd_exact", "spmm_bwd_sampled")
+            ks = [reg.get_gauge("spmm.group_k", layer=layer, op=o)
+                  for o in ops_]
+            shares = [reg.get_gauge("spmm.grouped_share", layer=layer, op=o)
+                      for o in ops_]
+            if backend == "jnp":
+                assert ks == [None] * 3 and shares == [None] * 3
+                continue
+            assert ks == [8, 8, 8]
+            assert shares[0] == fwd
+            assert shares[2] == grouped_share(
+                planner.cache.ops[layer].row_ptr, 8)
+    finally:
+        obs.reset()

@@ -91,6 +91,26 @@ def test_spmm_kernel_compiles_for_v5e(one_chip, d):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("d", [256, CLASSES, FEATURES])
+def test_spmm_kernel_compiles_for_v5e_on_a_plan(one_chip, d):
+    """The sampled backward's shape: a budget-0.1 plan of ``PLAN_PAD``
+    entries (sentinel padding included) over the full tile array, so the
+    grouped walk's tail path is compiled at each width too."""
+    a = _operand(one_chip)
+    n = ROW_BLOCKS * BLOCK
+
+    def spmm(blocks, sel, rows, cols, rptr, h):
+        return ops.bcoo_spmm(blocks, sel, rows, cols, h,
+                             n_row_blocks=ROW_BLOCKS, bm=BLOCK, bk=BLOCK,
+                             row_ptr=rptr)
+
+    plan = _spec(one_chip, (PLAN_PAD,), jnp.int32)
+    compiled = jax.jit(spmm).lower(
+        a.blocks, plan, plan, plan, a.row_ptr,
+        _spec(one_chip, (n, d))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.mark.parametrize("mode", ["rsc", "exact"])
 def test_gcn_train_step_compiles_for_v5e(one_chip, mode):
     """The jitted full-batch GCN train step (Pallas backend, sampled
