@@ -226,7 +226,9 @@ def reference_run(cell: spec.Cell, graph: dict, pseed: int, dtype=None):
     r = refresh_step(traffic)
     prob = reference.problem(graph, config["model"], config["block"])
     cfg = {"hidden": config["hidden"], "classes": graph["classes"],
-           "n_layers": config["n_layers"], "dropout": config["dropout"],
+           "n_layers": config["n_layers"], "batchnorm": config["batchnorm"],
+           "model_args": config.get("model_args", {}),
+           "dropout": config["dropout"],
            "lr": config["lr"], "rsc": traffic["rsc"],
            "budget": traffic["budget"], "step_frac": traffic["step_frac"],
            "refresh_every": r,

@@ -1,8 +1,11 @@
 """GCN (Kipf & Welling 2017), as the reference and the work counts see it.
 
 Layer l: ``H' = Ã · (dropout(H) W + b)`` with
-``Ã = D̃^-1/2 (A + I) D̃^-1/2``; batch norm and ReLU follow every layer
-but the last (in ``reference.py``).
+``Ã = D̃^-1/2 (A + I) D̃^-1/2``; batch norm (where the configuration sets
+``batchnorm``) and ReLU follow every layer but the last.
+
+Every function takes the configuration's sizes ``s``: ``feat_dim``,
+``hidden``, ``classes``, ``n_layers``, ``batchnorm``, ``model_args``.
 """
 import jax
 import numpy as np
@@ -18,30 +21,53 @@ def normalize(rows, cols, deg):
     return rows, cols, 1.0 / np.sqrt(dt[rows] * dt[cols])
 
 
-def init(key, dims, dense):
-    keys = jax.random.split(key, len(dims) - 1)
-    return {"lin": [dense(keys[l], dims[l], dims[l + 1])
-                    for l in range(len(dims) - 1)]}
+def layer_widths(s):
+    """Input and output width of each layer: the features, ``hidden``
+    between layers, the classes."""
+    return [s["feat_dim"]] + [s["hidden"]] * (s["n_layers"] - 1) \
+        + [s["classes"]]
 
 
-def layer(params, l, h, spmm, dot):
-    p = params["lin"][l]
-    return spmm(dot(h, p["w"]) + p["b"])
+def init(s, key, p):
+    """The parameter tree, with the program's key paths: ``p.dense(key,
+    d_in, d_out)`` and ``p.bn(d)`` make one dense map and one batch norm."""
+    d, n = layer_widths(s), s["n_layers"]
+    keys = jax.random.split(key, n)
+    return {"lin": [p.dense(keys[l], d[l], d[l + 1]) for l in range(n)],
+            "bn": [p.bn(d[l + 1]) if s["batchnorm"] and l < n - 1 else None
+                   for l in range(n)]}
 
 
-def sampled_layers(n_layers):
+def forward(s, params, x, f):
+    """Logits: ``f.dropout(h)``, ``f.spmm(l)(h)``, ``f.dot(a, b)`` and
+    ``f.bn(p, h)`` are the reference's operations."""
+    n = len(params["lin"])
+    h = x
+    for l in range(n):
+        p = params["lin"][l]
+        h = f.spmm(l)(f.dot(f.dropout(h), p["w"]) + p["b"])
+        if l < n - 1:
+            if params["bn"][l] is not None:
+                h = f.bn(params["bn"][l], h)
+            h = jax.nn.relu(h)
+    return h
+
+
+def sampled_layers(s):
     """Layers whose backward SpMM RSC samples: every one."""
-    return list(range(n_layers))
+    return list(range(s["n_layers"]))
 
 
-def spmm_widths(dims):
+def spmm_widths(s):
     """Widths of one step's forward SpMMs and backward SpMMs: GCN
     propagates each layer's output, and every weight gradient needs the
     backward SpMM."""
-    fwd = list(dims[1:])
+    fwd = layer_widths(s)[1:]
     return fwd, list(fwd)
 
 
-def dense_maps(dims):
-    """(d_in, d_out) of each layer's dense maps."""
-    return [[(dims[l], dims[l + 1])] for l in range(len(dims) - 1)]
+def dense_maps(s):
+    """(d_in, d_out) of each layer's dense maps; the first layer's input
+    carries no gradient."""
+    d = layer_widths(s)
+    return [[(d[l], d[l + 1])] for l in range(len(d) - 1)]

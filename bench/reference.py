@@ -1,5 +1,4 @@
-"""Plain reference of full-batch GCN and GraphSAGE training steps, RSC
-included.
+"""Plain reference of full-batch GNN training steps, RSC included.
 
 Straightforward ``jax.numpy`` and numpy from the published equations, with
 nothing of the program imported and nothing it made taken: the reference
@@ -7,9 +6,11 @@ builds its own propagation matrix from the benchmark's edge list, draws its
 own weights and dropout masks from the seed, picks its own column-row
 pairs and runs its own Adam.
 
-* each layer is ``ReLU(BN(layer(dropout(H))))``, with no BN or ReLU
-  after the last; ``layer`` and the propagation matrix are the model's
-  (``bench/models/<model>.py``: GCN, GraphSAGE-mean);
+* the model is its file, ``bench/models/<model>.py``: the propagation
+  matrix (``normalize``), the parameter tree (``init``), the layer stack
+  (``forward``: where dropout, SpMM, dense maps, batch norm and ReLU go)
+  and which layers RSC samples (``sampled_layers``), all from the
+  configuration's sizes (``SIZES``); what every model shares is here;
 * masked mean softmax cross-entropy over the training nodes;
 * Adam (Kingma & Ba 2015), no weight decay;
 * RSC (Liu et al. 2022, Sec. 3): the forward SpMM is exact; the backward
@@ -28,14 +29,17 @@ sides: nodes are relabelled by descending degree (stable, ties by id),
 rows are padded to a multiple of the block, pairs are chosen and costed in
 blocks of ``block`` nodes, weights are He-normal with zero biases, BN
 statistics run over the real nodes, and each dropout mask is a Bernoulli
-draw from the ``rbg`` generator keyed by the threefry key of its layer
-(split once per step from ``PRNGKey(seed + 1)``, then once per layer).
+draw from the ``rbg`` generator keyed by a threefry key of its own
+(split once per step from ``PRNGKey(seed + 1)``, then once per dropout,
+in the order the model's ``forward`` draws them).
 
 The SpMM is a gather and a segment sum over the edge list, exact in f32;
 dense products run at ``highest`` precision. ``dtype=jnp.bfloat16`` gives
 the control: every array and every operation in bfloat16.
 """
 from __future__ import annotations
+
+import types
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +48,8 @@ import numpy as np
 import spec
 
 B1, B2, EPS = 0.9, 0.999, 1e-8
+# the configuration's sizes a model's file reads, besides ``feat_dim``
+SIZES = ("hidden", "classes", "n_layers", "batchnorm", "model_args")
 
 
 def problem(graph: dict, model: str, block: int) -> dict:
@@ -80,22 +86,19 @@ def problem(graph: dict, model: str, block: int) -> dict:
             "fro": float(np.sqrt(np.sum(vals.astype(np.float64) ** 2)))}
 
 
-def init_params(model: str, key, d_in: int, hidden: int, classes: int,
-                n_layers: int) -> dict:
-    """He-normal weights, zero biases; batch norm after all but the last
-    layer."""
-    dims = [d_in] + [hidden] * (n_layers - 1) + [classes]
-
+def init_params(mod, key, sizes: dict) -> dict:
+    """The model's parameter tree: He-normal weights, zero biases, batch
+    norms at unit scale and zero shift."""
     def dense(k, i, o):
         return {"w": jax.random.normal(k, (i, o), jnp.float32)
                 * float(np.sqrt(2.0 / i)),
                 "b": jnp.zeros((o,), jnp.float32)}
 
-    params = spec.model_module(model).init(key, dims, dense)
-    params["bn"] = [{"g": jnp.ones((dims[l + 1],), jnp.float32),
-                     "b": jnp.zeros((dims[l + 1],), jnp.float32)}
-                    if l < n_layers - 1 else None for l in range(n_layers)]
-    return params
+    def bn(d):
+        return {"g": jnp.ones((d,), jnp.float32),
+                "b": jnp.zeros((d,), jnp.float32)}
+
+    return mod.init(sizes, key, types.SimpleNamespace(dense=dense, bn=bn))
 
 
 def _dropout(h, rate, key):
@@ -130,14 +133,17 @@ def device_data(prob: dict, dtype) -> dict:
             "train": jnp.asarray(prob["train"] & valid)}
 
 
-def make_loss(model: str, n_pad: int, dropout: float):
+def make_loss(mod, sizes: dict, n_pad: int, dropout: float):
     """``loss(params, taps, keeps, key, data)``.
 
-    ``taps[j]`` is added to the output of sampled layer ``j``'s SpMM, so
-    its gradient is that output's gradient; ``keeps[j]`` (1 or 0 per node)
-    says which rows of it the backward SpMM keeps.
+    The model's ``forward`` runs the layer stack on the reference's
+    operations. ``dropout(h)`` draws the next key split from ``key``.
+    ``spmm(l)`` is layer ``l``'s propagation; where RSC samples the layer,
+    as the ``j``-th of ``sampled_layers``, ``taps[j]`` is added to its
+    output, so that its gradient is that output's gradient, and its
+    backward keeps the rows that ``keeps[j]`` (1 or 0 per node) marks.
     """
-    mod = spec.model_module(model)
+    layers = mod.sampled_layers(sizes)
 
     def loss(params, taps, keeps, key, data):
         def spmm(h):
@@ -152,19 +158,16 @@ def make_loss(model: str, n_pad: int, dropout: float):
                 return jax.lax.stop_gradient(y - z) + z + taps[j]
             return f
 
-        n_layers = len(params["bn"])
-        layers = mod.sampled_layers(n_layers)
-        h = data["x"]
-        for l in range(n_layers):
+        def drop(h):
+            nonlocal key
             key, sub = jax.random.split(key)
-            h = _dropout(h, dropout, sub)
-            op = sampled(layers.index(l)) if l in layers else spmm
-            hp = mod.layer(params, l, h, op, _dot)
-            if l < n_layers - 1:
-                if params["bn"][l] is not None:
-                    hp = _batchnorm(params["bn"][l], hp, data["valid"])
-                hp = jax.nn.relu(hp)
-            h = hp
+            return _dropout(h, dropout, sub)
+
+        ops = types.SimpleNamespace(
+            dropout=drop, dot=_dot,
+            spmm=lambda l: sampled(layers.index(l)) if l in layers else spmm,
+            bn=lambda p, h: _batchnorm(p, h, data["valid"]))
+        h = mod.forward(sizes, params, data["x"], ops)
         logp = jax.nn.log_softmax(h, axis=-1)
         per = -jnp.take_along_axis(
             logp, data["labels"][:, None], axis=-1)[:, 0]
@@ -233,26 +236,24 @@ def run(prob: dict, cfg: dict, seed: int, steps: int, *, grad_steps=(0,),
         param_steps=(0,), dtype=jnp.float32) -> dict:
     """``steps`` training steps from the seed's initial weights.
 
-    ``cfg`` holds the model's sizes and, for RSC, ``rsc``, ``budget``,
-    ``step_frac``, ``refresh_every`` and ``rsc_steps`` (the steps before
-    switch-back). Returns the loss of each step, the gradient of each step
-    in ``grad_steps`` and the weights before each step in ``param_steps``,
-    all as float32 numpy pytrees.
+    ``cfg`` holds the model's sizes (``SIZES``), ``dropout``, ``lr`` and,
+    for RSC, ``rsc``, ``budget``, ``step_frac``, ``refresh_every`` and
+    ``rsc_steps`` (the steps before switch-back). Returns the loss of each
+    step, the gradient of each step in ``grad_steps`` and the weights
+    before each step in ``param_steps``, all as float32 numpy pytrees.
     """
     mod = spec.model_module(prob["model"])
-    params = init_params(prob["model"], jax.random.PRNGKey(seed),
-                         prob["x"].shape[1], cfg["hidden"], cfg["classes"],
-                         cfg["n_layers"])
+    sizes = {"feat_dim": prob["x"].shape[1],
+             **{k: cfg[k] for k in SIZES}}
+    params = init_params(mod, jax.random.PRNGKey(seed), sizes)
     params = jax.tree.map(lambda p: p.astype(dtype), params)
-    dims = [prob["x"].shape[1]] + [cfg["hidden"]] * (cfg["n_layers"] - 1) \
-        + [cfg["classes"]]
-    layers = mod.sampled_layers(cfg["n_layers"])
-    widths = [mod.spmm_widths(dims)[0][l] for l in layers]
+    layers = mod.sampled_layers(sizes)
+    widths = [mod.spmm_widths(sizes)[0][l] for l in layers]
     m = jax.tree.map(jnp.zeros_like, params)
     v = jax.tree.map(jnp.zeros_like, params)
     data = device_data(prob, dtype)
     vg = jax.jit(jax.value_and_grad(
-        make_loss(prob["model"], prob["n_pad"], cfg["dropout"]),
+        make_loss(mod, sizes, prob["n_pad"], cfg["dropout"]),
         argnums=(0, 1)))
     update = jax.jit(_adam, static_argnums=(5,))
     taps = [jnp.zeros((prob["n_pad"], d), dtype) for d in widths]

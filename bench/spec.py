@@ -10,8 +10,10 @@ a cell is a file of its own, found from its name alone:
   ``bench/limits/<workload>.json``;
 * per-layer metric: ``bench/metrics/<metric>.py``, a module with
   ``read(ctx) -> float | None``;
-* model (the configuration's ``model``): ``bench/models/<model>.py``, its
-  equations for the plain reference and the work counts.
+* model (the configuration's ``model``): ``bench/models/<model>.py``, from
+  the configuration's sizes its parameter tree and whole layer stack for
+  the plain reference, and its SpMM widths and dense maps for the work
+  counts.
 
 A new cell, mix or metric is new files plus new entries in
 ``BENCHMARK.json``; no code here changes.

@@ -43,6 +43,14 @@ def test_worst_step_and_worst_leaf():
     assert g["update_gap"] == pytest.approx(0.1 / 0.3)
 
 
+def test_first_loss_gap_reads_step_zero_alone():
+    side = run([2.002, 1.5, 1.26, 1.0, 0.9], REF["grads"][0],
+               REF["params"][0], REF["params"][compare.UPDATE_STEPS])
+    g = compare.gaps(side, REF, R)
+    assert g["first_loss_gap"] == pytest.approx(0.002 / 2.0)
+    assert g["loss_gap"] == pytest.approx(0.06 / 1.2)
+
+
 def test_refresh_numbers_read_the_steps_after_the_refresh():
     side = run([2.0, 1.5, 1.2, 1.01, 0.9], REF["grads"][0],
                REF["params"][0], REF["params"][compare.UPDATE_STEPS],

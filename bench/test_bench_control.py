@@ -27,6 +27,19 @@ def test_control_fails_and_program_passes(seed):
     assert not ok, r
 
 
+@pytest.mark.parametrize("seed", SEEDS)
+def test_sage_control_fails_on_the_first_step(seed):
+    cell = small("sage-reddit-rsc")
+    r = control.readings(cell, seed, control=True, fault=False,
+                         require_chip=False)
+    ok, _ = compare.verdict(r["program"], cell.limits)
+    assert ok, r
+    ok, checks = compare.verdict(r["control"], cell.limits)
+    assert not ok, r
+    first = checks["first_loss_gap"]
+    assert first["value"] > first["limit"], r
+
+
 @pytest.mark.parametrize("workload", ["gcn-reddit-rsc", "sage-reddit-rsc"])
 @pytest.mark.parametrize("fault", ["lowest_blocks", "rescaled_sample"])
 def test_sampled_faults_move_the_refresh_gradient(workload, fault):

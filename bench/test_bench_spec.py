@@ -1,18 +1,24 @@
 import json
 import shutil
 
+import numpy as np
 import pytest
 
 import compare
+import graphgen
+import reference
 import spec
+import work
 
 BENCH = json.loads((spec.ROOT / "BENCHMARK.json").read_text())
 
 
 @pytest.mark.parametrize("workload", [w["name"] for w in BENCH["workloads"]])
 def test_every_cell_finds_its_files_by_name(workload):
+    from repro.models.gnn import MODELS
     cell = spec.load_cell(workload)
-    assert cell.config["model"] in ("gcn", "graphsage")
+    assert (spec.BENCH / "models" / f"{cell.config['model']}.py").is_file()
+    assert cell.config["model"] in MODELS
     assert cell.traffic["mode"] == "full_batch"
     assert cell.limits and set(cell.limits) <= set(compare.NUMBERS)
     assert {m["name"] for m in cell.end_to_end} >= {"epoch_s", "setup_s"}
@@ -75,6 +81,17 @@ def test_a_new_cell_is_new_files_and_entries(tmp_path):
     assert [m["name"] for m in cell.per_layer] == ["refresh_count"]
     read = spec.metric_reader("refresh_count", root=tmp_path)
     assert read(type("C", (), {"counts": {"rsc_steps": 40}})) == 4.0
+    # the model's own file gives the reference and the work counts
+    cell.config.update(nodes=512, classes=4, feat_dim=8, hidden=16)
+    graph = graphgen.generate(cell.config, seed=3)
+    prob = reference.problem(graph, "gcnii", cell.config["block"])
+    cfg = {k: cell.config[k] for k in
+           ("hidden", "n_layers", "batchnorm", "dropout", "lr")}
+    out = reference.run(prob, dict(cfg, classes=4, model_args={}), 3, 1)
+    assert len(out["losses"]) == 1 and np.isfinite(out["losses"][0])
+    assert "proj_in" in out["params"][0]
+    shape = work.shape_of(cell.config, graph)
+    assert work.model_flops(shape) > work.model_flops(shape, train=False) > 0
 
 
 def test_unknown_workload_is_refused():

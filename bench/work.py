@@ -50,19 +50,15 @@ def shape_of(config: dict, graph: dict) -> dict:
         graph["rows"], graph["cols"], deg)
     return {"model": config["model"], "n_layers": config["n_layers"],
             "hidden": config["hidden"], "classes": graph["classes"],
+            "model_args": config.get("model_args", {}),
             "feat_dim": int(graph["features"].shape[1]), "nodes": nodes,
             "nnz": int(rows.shape[0])}
 
 
-def _dims(s: dict) -> list[int]:
-    return [s["feat_dim"]] + [s["hidden"]] * (s["n_layers"] - 1) \
-        + [s["classes"]]
-
-
 def spmm_widths(s: dict) -> tuple[list[int], list[int]]:
     """Widths of the forward SpMMs and of the backward SpMMs of one step
-    (the model's, ``bench/models/<model>.py``)."""
-    return spec.model_module(s["model"]).spmm_widths(_dims(s))
+    (the model's, ``bench/models/<model>.py``, from the sizes ``s``)."""
+    return spec.model_module(s["model"]).spmm_widths(s)
 
 
 def spmm_work(nnz: int, n: int, d: int, frac: float = 1.0):
@@ -95,14 +91,14 @@ def spmm_least_seconds(s: dict, counts: dict, budget: float,
 def model_flops(s: dict, train: bool = True) -> float:
     """Model FLOPs of one training step (or one evaluation)."""
     n, nnz = s["nodes"], s["nnz"]
-    maps = spec.model_module(s["model"]).dense_maps(_dims(s))
+    maps = spec.model_module(s["model"]).dense_maps(s)
     fwd_spmm, bwd_spmm = spmm_widths(s)
     per_layer = [sum(2.0 * n * i * o for i, o in m) for m in maps]
     total = sum(per_layer) + sum(2.0 * nnz * d for d in fwd_spmm)
     if not train:
         return total
-    # weight gradients of every layer; input gradients of all but the
-    # first, whose input (the features) carries none
+    # weight gradients of every dense map; input gradients of all but
+    # the first layer's, whose input (the features) carries none
     return (total + sum(per_layer) + sum(per_layer[1:])
             + sum(2.0 * nnz * d for d in bwd_spmm))
 
